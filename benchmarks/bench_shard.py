@@ -56,6 +56,7 @@ from repro.analysis.measure import from_hlo
 from repro.core import sam as sam_lib
 from repro.core.types import ControllerConfig, MemoryConfig
 from repro.distributed import mem_shard
+from repro.launch.mesh import make_mesh
 
 OUT_DIR = "experiments/bench"
 OUT_PATH = os.path.join(OUT_DIR, "BENCH_shard.json")
@@ -238,7 +239,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     sizes = [256, 1024] if args.quick else [256, 1024, 4096]
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     results = []
     for n in sizes:
         for rec in (compile_mesh_step(mesh, n),

@@ -11,7 +11,7 @@ Organization mirrors the claims:
 * **SAM read** — the LSH candidate read is flat in N; the exact read is
   declared-linear (the similarity sweep is inherently O(N·W) — the paper
   point is that serving uses the ANN path); on the Pallas backends the
-  exact read is ONE `_sweep_kernel` dispatch with no top_k/sort, and the
+  exact read is ONE `fused_read_sweep` dispatch with no top_k/sort, and the
   composed control must trip that detector.
 * **Fused write** — the scratch-row layout stages no O(N·W) pad/slice
   copy of the buffer (`scratch_copy` lint); the legacy layout on the
@@ -51,6 +51,7 @@ from repro.core.cell import SAMCell
 from repro.core.types import ControllerConfig, MemoryConfig
 from repro.distributed import mem_shard
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # Serving-scale shapes for the single-device read/step contracts.
@@ -146,10 +147,10 @@ def sam_read_exact_kernel():
         name="sam_read_exact_kernel", build=_build_sam_read_exact,
         sizes=dict(_SIZES), points=(256, 1024), quick_points=None,
         dispatches={"pallas_call": 1, "top_k": 0, "sort": 0},
-        kernels={"_sweep_kernel": 1},
+        kernels={"fused_read_sweep": 1},
         backends=("pallas-interpret",),
         notes="On the Pallas backend the exact read is ONE fused "
-              "_sweep_kernel dispatch: no top_k, no sort "
+              "fused_read_sweep dispatch: no top_k, no sort "
               "(tests/test_fused_read.py's acceptance guard).")
 
 
@@ -493,7 +494,7 @@ def _mesh_cfg(n: int, *, ann: str = "exact") -> sam_lib.SAMConfig:
 
 
 def _mesh1d():
-    return jax.make_mesh((_MSHARDS,), ("model",))
+    return make_mesh((_MSHARDS,), ("model",))
 
 
 def _mesh_meminfo(n: int, *, batch=_MB):

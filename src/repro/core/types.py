@@ -106,7 +106,8 @@ class MemoryConfig:
     ann: str = "exact"
     # Kernel backend: 'ref' | 'pallas' | 'pallas-interpret' | a registered
     # custom name (repro.kernels.registry). None -> $REPRO_KERNEL_BACKEND
-    # -> 'ref'. Trace-time static; threaded through every memory op.
+    # -> 'pallas' on a TPU, 'ref' elsewhere. Trace-time static; threaded
+    # through every memory op.
     backend: Optional[str] = None
     # Storage dtype of the memory rows: 'float32' | 'bfloat16' | 'int8'.
     # Reads upcast gathered rows to float32 before the similarity/softmax

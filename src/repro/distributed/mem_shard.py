@@ -72,7 +72,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.types import (ANN_LEAVES, LA_SCRATCH, SCRATCH_ROWS,
@@ -604,8 +603,8 @@ def ckpt_layout(ctx: Optional[MemShardCtx] = None):
 # canonical dispatch, one shard at a time.
 
 def _smap(ctx, body, in_specs, out_specs):
-    return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _bentry(ctx, batch: int):

@@ -69,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("q_block", "kv_block",
                                              "interpret"))
 def flash_attention(q, k, v, *, q_block: int = 512, kv_block: int = 512,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Causal GQA flash attention.
 
     q: (B, S, H, D); k, v: (B, S, Hkv, D) -> (B, S, H, D)."""
@@ -109,5 +109,6 @@ def flash_attention(q, k, v, *, q_block: int = 512, kv_block: int = 512,
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return jnp.moveaxis(out.reshape(B, H, S, D), 1, 2)

@@ -6,13 +6,15 @@ softmax / weighted-sum tail — each materializing an intermediate in HBM.
 These kernels collapse the whole read into **one** `pallas_call`:
 
 * `fused_read_sweep` — the exact ("linear index") read. Grid
-  (B·H, N/block_n), sequential over tiles: each tile computes cosine
-  similarities on the MXU, keeps a running global top-K in VMEM scratch
-  (values, indices, and the raw candidate *rows*, so no second gather
-  pass ever touches HBM), and the final tile applies key strength,
-  softmax, and the weighted sum in-register. HBM traffic is the one
-  O(N·W) memory stream — the intermediates (sims, top-K merge buffers,
-  gathered rows) never exist outside VMEM.
+  (B, N/block_n), sequential over tiles: each tile computes the cosine
+  similarities of all H heads on the MXU, merges them into a running
+  per-head top-K in VMEM scratch (values, indices, and the raw candidate
+  *rows*, so no second gather pass ever touches HBM — the selection
+  helpers are shared with `kernels/topk_read.py`), and the final tile
+  applies key strength, softmax, and the weighted sum in-register. HBM
+  traffic is one O(N·W) memory stream per batch row, shared by its H
+  heads — the intermediates (sims, top-K merge buffers, gathered rows)
+  never exist outside VMEM.
 
 * `fused_read_candidates` — the ANN-mode read over a pre-deduped signed
   candidate set from the LSH index. The candidate ids are scalar-
@@ -51,7 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CONSUMED = -3e30          # below any cosine sim and the -1e9 validity mask
+from repro.kernels.topk_read import CONSUMED, merge_topk, sims_tile
+
 _NEG = -1e9                # finish_candidate_read's invalid-selection mask
 _IMAX = jnp.iinfo(jnp.int32).max
 
@@ -72,10 +75,10 @@ def _softmax_tail(vals, valid, beta):
     `addressing.finish_candidate_read`: scaled sims masked to -1e9 where
     invalid, softmax, invalid weights zeroed, renormalized."""
     sel = jnp.where(valid, vals * beta, _NEG)
-    e = jnp.exp(sel - jnp.max(sel))
-    w = e / jnp.sum(e)
+    e = jnp.exp(sel - jnp.max(sel, axis=-1, keepdims=True))
+    w = e / jnp.sum(e, axis=-1, keepdims=True)
     w = jnp.where(valid, w, 0.0)
-    return w / jnp.maximum(jnp.sum(w), 1e-6)
+    return w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-6)
 
 
 # --------------------------------------------------------------------------
@@ -86,67 +89,46 @@ def _sweep_kernel(q_ref, m_ref, beta_ref, *rest, k: int, block_n: int,
                   tiles: int, quantized: bool):
     if quantized:
         s_ref, read_ref, w_ref, idx_ref, vals_s, idx_s, rows_s = rest
+        scale = s_ref[...]
     else:
-        s_ref = None
         read_ref, w_ref, idx_ref, vals_s, idx_s, rows_s = rest
+        scale = None
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        vals_s[0, :] = jnp.full((k,), _CONSUMED, jnp.float32)
-        idx_s[0, :] = jnp.full((k,), _IMAX, jnp.int32)
-        rows_s[:, :] = jnp.zeros(rows_s.shape, jnp.float32)
+        vals_s[...] = jnp.full(vals_s.shape, CONSUMED, jnp.float32)
+        idx_s[...] = jnp.zeros(idx_s.shape, jnp.int32)
+        rows_s[...] = jnp.zeros(rows_s.shape, jnp.float32)
 
-    q = q_ref[0, :].astype(jnp.float32)
-    m = m_ref[0, :, :].astype(jnp.float32)
-    if quantized:
-        # In-VMEM dequantization: the HBM stream stays int8 rows + one f32
-        # scale per row (~4x less traffic than f32 rows); everything after
-        # this multiply is the unquantized kernel unchanged.
-        m = m * s_ref[0, :][:, None]
-    qn = _norm_row(q)
-    mnorm = jax.lax.rsqrt(jnp.sum(m * m, axis=-1) + 1e-6)
-    sims = jnp.dot(m, qn, preferred_element_type=jnp.float32) * mnorm
-    base = t * block_n
-
-    # Local top-K of this tile (K argmax passes; argmax prefers the lowest
-    # j on ties, i.e. the lowest global index).
-    lv, li, lr = [], [], []
-    for _ in range(k):
-        j = jnp.argmax(sims)
-        lv.append(sims[j])
-        li.append((base + j).astype(jnp.int32))
-        lr.append(_take_row(m, j))
-        sims = sims.at[j].set(_CONSUMED)
-
-    # Merge scratch + local (2K entries) back into scratch, ordered by
-    # (value descending, index ascending) — `lax.top_k`'s tie convention.
-    cv = jnp.concatenate([vals_s[0, :], jnp.stack(lv)])
-    ci = jnp.concatenate([idx_s[0, :], jnp.stack(li)])
-    cr = jnp.concatenate([rows_s[:, :], jnp.stack(lr)], axis=0)
+    # In-VMEM upcast/dequantization: the HBM stream stays in the storage
+    # dtype (int8 rows + one f32 scale per row, ~4x less than f32 rows).
+    m = m_ref[...].astype(jnp.float32)
+    sims = sims_tile(q_ref[...], m, scale)
+    vals, idx, rows = merge_topk(sims, t * block_n, vals_s[...], idx_s[...],
+                                 m=m, scale=scale,
+                                 rows=[rows_s[i] for i in range(k)])
+    vals_s[...] = vals
+    idx_s[...] = idx
     for i in range(k):
-        vmax = jnp.max(cv)
-        j = jnp.argmin(jnp.where(cv == vmax, ci, _IMAX))
-        vals_s[0, i] = cv[j]
-        idx_s[0, i] = ci[j]
-        rows_s[i, :] = _take_row(cr, j)
-        cv = cv.at[j].set(_CONSUMED)
-        ci = ci.at[j].set(_IMAX)
+        rows_s[i] = rows[i]
 
     @pl.when(t == tiles - 1)
     def _emit():
         # Exact selections are always valid (every swept row is real).
-        w = _softmax_tail(vals_s[0, :], True, beta_ref[0, 0])
-        read_ref[0, :] = jnp.dot(w, rows_s[:, :],
-                                 preferred_element_type=jnp.float32)
-        w_ref[0, :] = w
-        idx_ref[0, :] = idx_s[0, :]
+        w = _softmax_tail(vals_s[...], True, beta_ref[...])
+        read = w[:, 0:1] * rows_s[0]
+        for i in range(1, k):
+            read = read + w[:, i:i + 1] * rows_s[i]
+        read_ref[...] = read
+        w_ref[...] = w
+        idx_ref[...] = idx_s[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret",
                                              "valid_n"))
 def fused_read_sweep(q: jax.Array, mem: jax.Array, beta: jax.Array, *,
-                     k: int, block_n: int = 512, interpret: bool = True,
+                     k: int, block_n: int = 512, interpret: bool = False,
                      valid_n: Optional[int] = None,
                      mem_scale: Optional[jax.Array] = None):
     """q: (B, H, W), mem: (B, N, W), beta: (B, H) -> (read (B, H, W) f32,
@@ -162,45 +144,41 @@ def fused_read_sweep(q: jax.Array, mem: jax.Array, beta: jax.Array, *,
     assert N % block_n == 0, (N, block_n)
     assert block_n >= k, (block_n, k)
     tiles = N // block_n
-    qf = q.reshape(B * H, W)
-    bf = beta.reshape(B * H, 1).astype(jnp.float32)
     quantized = mem_scale is not None
 
-    in_specs = [
-        pl.BlockSpec((1, W), lambda bh, t: (bh, 0)),
-        pl.BlockSpec((1, block_n, W), lambda bh, t: (bh // H, t, 0)),
-        pl.BlockSpec((1, 1), lambda bh, t: (bh, 0)),
-    ]
-    operands = [qf, mem, bf]
+    head_spec = lambda last: pl.BlockSpec((None, H, last),  # noqa: E731
+                                          lambda b, t: (b, 0, 0))
+    in_specs = [head_spec(W),
+                pl.BlockSpec((None, block_n, W), lambda b, t: (b, t, 0)),
+                head_spec(1)]
+    operands = [q, mem, beta.reshape(B, H, 1).astype(jnp.float32)]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, block_n),
-                                     lambda bh, t: (bh // H, t)))
-        operands.append(mem_scale.astype(jnp.float32))
+        # (B, 1, rows): a lane-major (1, block_n) scale row per tile.
+        in_specs.append(pl.BlockSpec((None, 1, block_n),
+                                     lambda b, t: (b, 0, t)))
+        operands.append(mem_scale.astype(jnp.float32)[:, None, :])
 
-    read, w, idx = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_sweep_kernel, k=k, block_n=block_n, tiles=tiles,
                           quantized=quantized),
-        grid=(B * H, tiles),
+        grid=(B, tiles),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, W), lambda bh, t: (bh, 0)),
-            pl.BlockSpec((1, k), lambda bh, t: (bh, 0)),
-            pl.BlockSpec((1, k), lambda bh, t: (bh, 0)),
-        ],
+        out_specs=[head_spec(W), head_spec(k), head_spec(k)],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, W), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, k), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, H, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, k), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.int32),
-            pltpu.VMEM((k, W), jnp.float32),
+            pltpu.VMEM((H, k), jnp.float32),
+            pltpu.VMEM((H, k), jnp.int32),
+            pltpu.VMEM((k, H, W), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_read_sweep",
     )(*operands)
-    return (read.reshape(B, H, W), w.reshape(B, H, k),
-            idx.reshape(B, H, k))
 
 
 # --------------------------------------------------------------------------
@@ -219,7 +197,7 @@ def _cand_kernel(cc_ref, cs_ref, q_ref, beta_ref, m_ref, *rest,
 
     @pl.when(c == 0)
     def _init():
-        vals_s[0, :] = jnp.full((k,), _CONSUMED, jnp.float32)
+        vals_s[0, :] = jnp.full((k,), CONSUMED, jnp.float32)
         # Distinct descending sentinels: the first K insertions each evict
         # a different empty slot (eviction picks the max-pos minimum).
         pos_s[0, :] = _IMAX - jnp.arange(k, dtype=jnp.int32)
@@ -262,7 +240,7 @@ def _cand_kernel(cc_ref, cs_ref, q_ref, beta_ref, m_ref, *rest,
             ov.append(cv[j])
             osig.append(sig_s[0, j])
             orows.append(_take_row(rows_s[:, :], j))
-            cv = cv.at[j].set(_CONSUMED)
+            cv = cv.at[j].set(CONSUMED)
             cp = cp.at[j].set(_IMAX)
         vals = jnp.stack(ov)
         sig = jnp.stack(osig)
@@ -277,7 +255,7 @@ def _cand_kernel(cc_ref, cs_ref, q_ref, beta_ref, m_ref, *rest,
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def fused_read_candidates(q: jax.Array, mem: jax.Array, beta: jax.Array,
                           cand_idx: jax.Array, *, k: int,
-                          interpret: bool = True,
+                          interpret: bool = False,
                           mem_scale: Optional[jax.Array] = None):
     """ANN-mode fused read. q: (B, H, W), mem: (B, N, W), beta: (B, H),
     cand_idx: (B, H, C) *signed, pre-deduped* candidate ids (-1 = invalid).
@@ -332,6 +310,7 @@ def fused_read_candidates(q: jax.Array, mem: jax.Array, beta: jax.Array,
             jax.ShapeDtypeStruct((B * H, k), jnp.int32),
         ],
         interpret=interpret,
+        name="fused_read_candidates",
     )(cc, cs, *operands)
     return (read.reshape(B, H, W), w.reshape(B, H, k),
             idx.reshape(B, H, k))

@@ -33,6 +33,11 @@ def count_primitives(fn, *args, **kwargs) -> collections.Counter:
         jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)
     else:
         jaxpr = jax.make_jaxpr(fn)(*args)
+    return count_jaxpr(jaxpr)
+
+
+def count_jaxpr(jaxpr) -> collections.Counter:
+    """`count_primitives` of an already traced (closed) jaxpr."""
     counts: collections.Counter = collections.Counter()
     _walk(jaxpr.jaxpr, counts)
     return counts
@@ -50,20 +55,10 @@ def kernel_names(counts: collections.Counter) -> collections.Counter:
 
 
 def _pallas_kernel_name(params) -> str:
-    """Best-effort kernel name from a pallas_call eqn's params.
-
-    jax 0.4.x carries a ``name_and_src_info`` object with a ``.name``
-    attribute; older/newer layouts may expose a plain ``name`` param.
-    Returns ``"<unknown>"`` when neither is present rather than failing
-    the count.
-    """
-    info = params.get("name_and_src_info")
-    if info is not None and getattr(info, "name", None):
-        return str(info.name)
-    name = params.get("name")
-    if isinstance(name, str) and name:
-        return name
-    return "<unknown>"
+    """The kernel name a pallas_call eqn carries: every kernel in this repo
+    passes ``name=`` to `pl.pallas_call`, so an unnamed one reads as
+    ``"<unknown>"``."""
+    return params.get("name") or "<unknown>"
 
 
 def _walk(jaxpr, counts) -> None:

@@ -26,7 +26,7 @@ def _kernel(x_ref, planes_ref, out_ref, *, bits: int, tables: int):
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
 def lsh_hash(x: jax.Array, planes: jax.Array, *, block_r: int = 256,
-             interpret: bool = True):
+             interpret: bool = False):
     """x: (R, W), planes: (T, bits, W) -> bucket ids (R, T) int32."""
     R, W = x.shape
     T, bits, _ = planes.shape
@@ -43,5 +43,6 @@ def lsh_hash(x: jax.Array, planes: jax.Array, *, block_r: int = 256,
         out_specs=pl.BlockSpec((block_r, T), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, T), jnp.int32),
         interpret=interpret,
+        name="lsh_hash",
     )(xp, planes.reshape(T * bits, W))
     return out[:R]

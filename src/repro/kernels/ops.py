@@ -1,17 +1,20 @@
 """Backend-dispatched public wrappers for the kernel suite.
 
 Every op takes ``backend=`` (a name, a :class:`~repro.kernels.registry.
-KernelBackend`, or None → `REPRO_KERNEL_BACKEND` env var → ``"ref"``) and
+KernelBackend`, or None → `REPRO_KERNEL_BACKEND` env var → the platform
+default, ``"pallas"`` on a TPU and ``"ref"`` elsewhere) and
 routes to that backend's implementation, falling back to the pure-jnp
 oracles in `kernels/ref.py`. The backend choice is trace-time static.
 
-Silent-fallback rule (documented contract, covered by tests): the Pallas
+Shape-fallback rule (documented contract, covered by tests): the Pallas
 ``topk_read``, ``lra_topn`` and ``usage_argmin`` tile the N axis, so when
 N is not divisible by the (clamped) block size — or the input dtype is
-unsupported (float ``lra_topn``) — the op silently uses the reference
+unsupported (float ``lra_topn``) — the op uses the reference
 implementation instead of failing: results are identical, only the
-execution path differs. ``scatter_rows``, ``lsh_hash`` and
-``sparse_write_update`` have no shape restrictions.
+execution path differs. The served shapes always tile; `chip_smoke.py`
+proves it on the chip by finding the kernels in the compiled step.
+``scatter_rows``, ``lsh_hash`` and ``sparse_write_update`` have no shape
+restrictions.
 
 Scratch-row layout (docs/memory-model.md): the sweep ops take ``valid_n=``
 to restrict the scan to the logical rows [0, valid_n) of a persistent

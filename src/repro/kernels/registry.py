@@ -7,7 +7,7 @@ Three backends ship with the repo (see docs/kernels.md):
                              available, fully differentiable through XLA,
                              O(N·W) per step. The correctness baseline.
   * ``"pallas"``           — the compiled Pallas TPU kernels. The production
-                             path on TPU hardware.
+                             path on TPU hardware, and the default there.
   * ``"pallas-interpret"`` — the same Pallas kernels run through the Pallas
                              interpreter. Slow, but runs anywhere and is
                              bit-accurate to the kernel logic — used by the
@@ -18,7 +18,11 @@ Resolution order for ``resolve(spec)``:
   1. an explicit ``KernelBackend`` instance is used as-is;
   2. an explicit name (e.g. from ``MemoryConfig.backend``) is looked up;
   3. ``None`` falls back to the ``REPRO_KERNEL_BACKEND`` environment
-     variable, and finally to ``"ref"``.
+     variable, and finally to the platform default: ``"pallas"`` when
+     JAX's default backend is a TPU, ``"ref"`` everywhere else. On a TPU
+     the served path therefore runs the compiled kernels unless a caller
+     names another backend; ``"pallas-interpret"`` is only ever chosen by
+     name (the CPU tests).
 
 The backend name is trace-time static: it selects which primitives get
 staged into the jitted computation, it is not a runtime switch.
@@ -47,8 +51,9 @@ import dataclasses
 import os
 from typing import Callable, Mapping, Optional, Union
 
+import jax
+
 ENV_VAR = "REPRO_KERNEL_BACKEND"
-DEFAULT = "ref"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,12 +110,17 @@ def get(name: str) -> KernelBackend:
 BackendSpec = Union[None, str, KernelBackend]
 
 
+def platform_default() -> str:
+    """The backend an unnamed spec resolves to on this platform."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
 def resolve(spec: BackendSpec = None) -> KernelBackend:
     """Resolve a backend spec (instance | name | None) to a KernelBackend."""
     if isinstance(spec, KernelBackend):
         return spec
     if spec is None:
-        spec = os.environ.get(ENV_VAR) or DEFAULT
+        spec = os.environ.get(ENV_VAR) or platform_default()
     return get(spec)
 
 

@@ -57,7 +57,7 @@ def _combine_duplicates(idx: jax.Array, rows: jax.Array, dummy: int):
 @functools.partial(jax.jit, static_argnames=("mode", "interpret",
                                              "scratch_row"))
 def scatter_rows(mem: jax.Array, idx: jax.Array, rows: jax.Array,
-                 *, mode: str = "add", interpret: bool = True,
+                 *, mode: str = "add", interpret: bool = False,
                  scratch_row: Optional[int] = None):
     """mem: (B, N, W), idx: (B, J) int32, rows: (B, J, W) -> updated memory.
 
@@ -103,4 +103,5 @@ def _scatter_unique(mem: jax.Array, idx: jax.Array, rows: jax.Array,
         out_shape=jax.ShapeDtypeStruct(mem.shape, mem.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="scatter_rows",
     )(idx, mem, rows)

@@ -89,7 +89,8 @@ class ServeEngine:
         self._enter_mesh(mesh)
         self.replicas = self._resolve_replicas(lanes, mesh, replicas)
 
-        self.params = lm.init_params(jax.random.PRNGKey(param_seed), cfg)
+        self.param_seed = param_seed
+        self._init_params()
         self._build_batch(lanes)
 
         self.scheduler = Scheduler(lanes, replicas=self.replicas)
@@ -99,6 +100,14 @@ class ServeEngine:
                 capacity=session_capacity, spill_dir=spill_dir)
         self._out: dict[int, list] = {}             # request id -> tokens
         self.steps = 0
+
+    def _init_params(self) -> None:
+        """Random weights from ``param_seed`` in the compute dtype (serving
+        keeps no optimizer state, so there is no f32 master copy and no
+        per-step cast), placed on the live mesh by their logical axes."""
+        self.params = lm.init_params(jax.random.PRNGKey(self.param_seed),
+                                     self.cfg, dtype=self.cfg.compute_dtype,
+                                     mesh=self.mesh)
 
     def _enter_mesh(self, mesh) -> None:
         if mesh is not None:
@@ -142,7 +151,10 @@ class ServeEngine:
         self.lanes = lanes
         self.cache = lm.init_cache(cfg, lanes, self.max_len,
                                    per_lane_pos=True)
-        self.mem = lm.init_memory_states(cfg, lanes, per_lane_step=True)
+        # Memory leaves are born in the live shard layout; place them on
+        # the mesh too (slot-sharded), not on the default device.
+        self.mem = mem_shard.place_state(
+            lm.init_memory_states(cfg, lanes, per_lane_step=True))
         self._step_fn = make_engine_step(cfg)
         self._prefill_fn = make_prefill_scan(cfg)
         self._insert_fn = make_lane_insert(cfg)
@@ -201,11 +213,8 @@ class ServeEngine:
             return []
         self._prefill_scan_hop()
 
-        tokens = jnp.asarray(self._feed[:, None])
         next_tok, logits, self.cache, self.mem = self._step_fn(
-            self.params, self.cache, self.mem, tokens,
-            jnp.asarray(self._greedy), jnp.asarray(self._seeds),
-            jnp.asarray(self._counters))
+            self.params, self.cache, self.mem, *self._step_inputs())
         self.last_logits = logits     # (lanes, V); tests probe neighbours
         # Block on the sampled tokens: the tail-latency numbers the bench
         # records must measure compute, not JAX's async dispatch queue.
@@ -231,6 +240,19 @@ class ServeEngine:
                 self._evict_lane(lane)
                 finished.append(self._result(req))
         return finished
+
+    def _step_inputs(self):
+        """The per-lane host registers as the step's device inputs."""
+        return (jnp.asarray(self._feed[:, None]), jnp.asarray(self._greedy),
+                jnp.asarray(self._seeds), jnp.asarray(self._counters))
+
+    def trace_step(self):
+        """The engine step traced for the live batch state (a
+        `jax.stages.Traced`): its ``.jaxpr``, and ``.lower().compile()``
+        for an ahead-of-time compile whose program can be read (which
+        kernels it dispatches, its memory analysis)."""
+        return self._step_fn.trace(self.params, self.cache, self.mem,
+                                   *self._step_inputs())
 
     def run(self, requests=None) -> list:
         """Submit `requests` (optional) and step until the queue and all
@@ -279,6 +301,7 @@ class ServeEngine:
             self._stack.close()
             self._stack = contextlib.ExitStack()
             self._enter_mesh(mesh)
+            self._init_params()
         if replicas is None:
             replicas = self._mesh_data_degree(self.mesh)
         if lanes is None:

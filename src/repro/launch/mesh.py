@@ -9,12 +9,21 @@ from __future__ import annotations
 import warnings
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with Auto axes. The model code places arrays through
+    sharding constraints and GSPMD propagation (distributed/sharding.py),
+    which Explicit axes — `jax.make_mesh`'s default — reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for(devices: int, model_parallel: int = 16):
@@ -38,7 +47,7 @@ def make_mesh_for(devices: int, model_parallel: int = 16):
             f"elastic rescale onto this mesh re-layouts memory state to "
             f"{model} shard(s), not {model_parallel}.",
             UserWarning, stacklevel=2)
-    return jax.make_mesh((devices // model, model), ("data", "model"))
+    return make_mesh((devices // model, model), ("data", "model"))
 
 
 def make_memory_mesh(model_parallel: int = None):

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.distributed import mem_shard
-from repro.distributed.sharding import mesh_rules
+from repro.distributed.sharding import current_mesh, mesh_rules
+from repro.launch import compile_cache
 from repro.models import lm
 
 
@@ -57,7 +58,8 @@ def _select(logits, greedy: bool, key):
 
 def _serve(cfg, *, batch, prompt_len, gen_len, max_len, seed, greedy=True):
     key = jax.random.PRNGKey(seed)
-    params = lm.init_params(key, cfg)
+    params = lm.init_params(key, cfg, dtype=cfg.compute_dtype,
+                            mesh=current_mesh())
 
     cache = lm.init_cache(cfg, batch, max_len)
     if cfg.frontend == "audio":
@@ -163,6 +165,7 @@ def main():
                          "parallel degree (0 = no mesh); SAM-augmented "
                          "archs then run the mesh-native memory path")
     args = ap.parse_args()
+    compile_cache.enable()
     mesh = None
     if args.mesh_model:
         from repro.launch.mesh import make_memory_mesh

@@ -16,6 +16,7 @@ from repro.configs import get_config, reduced as reduce_cfg
 from repro.data.tokens import lm_token_batches
 from repro.distributed.fault_tolerance import ResilientLoop
 from repro.distributed.sharding import mesh_rules
+from repro.launch import compile_cache
 from repro.launch.steps import make_train_step
 from repro.launch.specs import concrete_batch
 from repro.models import lm
@@ -94,6 +95,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--accum", type=int, default=1)
     args = ap.parse_args()
+    compile_cache.enable()
     train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
           lr=args.lr, use_reduced=not args.full, ckpt_dir=args.ckpt_dir,
           accum=args.accum)

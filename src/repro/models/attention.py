@@ -375,7 +375,6 @@ def gqa_decode_sparse_sharded(params, cfg: ModelConfig, x, k_cache, v_cache,
     (The naive cross-shard gather version is kept for single-device tests;
     GSPMD lowers it by replicating the cache — refuted in §Perf C1.)"""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.sharding import current_mesh, logical_spec
 
     mesh = current_mesh()
@@ -418,10 +417,11 @@ def gqa_decode_sparse_sharded(params, cfg: ModelConfig, x, k_cache, v_cache,
         l = jax.lax.psum(l * corr, "model")
         return acc / jnp.maximum(l, 1e-20)[..., None]
 
-    o = shard_map(local, mesh=mesh,
-                  in_specs=(q_spec, cache_spec, cache_spec, cache_spec, P()),
-                  out_specs=q_spec,
-                  check_rep=False)(qg, k_cache, v_cache, ksum, pos)
+    o = jax.shard_map(local, mesh=mesh,
+                      in_specs=(q_spec, cache_spec, cache_spec, cache_spec,
+                                P()),
+                      out_specs=q_spec,
+                      check_vma=False)(qg, k_cache, v_cache, ksum, pos)
     o = o.reshape(B, 1, H, D).astype(x.dtype)
     mask = _head_mask(cfg, o.dtype)
     if mask is not None:

@@ -63,7 +63,8 @@ class MemoryLayerConfig:
     delta: float = 0.005
     segment: int = 512
     # Kernel backend for the memory ops ('ref' | 'pallas' |
-    # 'pallas-interpret' | registered custom; None -> env default).
+    # 'pallas-interpret' | registered custom; None -> the platform default,
+    # 'pallas' on a TPU and 'ref' elsewhere — kernels/registry.py).
     backend: "str | None" = None
     # Storage dtype of the memory rows ('float32' | 'bfloat16' | 'int8'):
     # bfloat16 halves the (B, N+1, W) buffer; 'int8' quarters it, storing

@@ -7,6 +7,7 @@ used by the sharding rules."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -49,11 +50,25 @@ def stack_defs(defs, num: int):
         defs, is_leaf=_is_def)
 
 
-def init_from_defs(key, defs, dtype):
+@functools.lru_cache(maxsize=None)
+def _leaf_init(d: ParamDef, dtype, sharding):
+    """One leaf's jitted initializer: the f32 draw, the scale and the cast
+    fuse on the device, so a bf16 leaf never exists in f32 as a whole, and
+    with ``sharding`` each device makes only its own shard."""
+    return jax.jit(lambda k: d.initialize(k, dtype), out_shardings=sharding)
+
+
+def init_from_defs(key, defs, dtype, shardings=None):
+    """Initialize every ParamDef in ``defs`` from ``key``, leaf by leaf.
+    ``shardings`` (a matching tree of shardings) places each leaf as it is
+    made."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=_is_def)
     keys = jax.random.split(key, len(leaves))
+    shards = ([None] * len(leaves) if shardings is None
+              else treedef.flatten_up_to(shardings))
     return jax.tree.unflatten(
-        treedef, [d.initialize(k, dtype) for d, k in zip(leaves, keys)])
+        treedef, [_leaf_init(d, dtype, s)(k)
+                  for d, k, s in zip(leaves, keys, shards)])
 
 
 def abstract_from_defs(defs, dtype):

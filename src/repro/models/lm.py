@@ -9,13 +9,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard
+from repro.distributed.sharding import named_sharding, shard
 from repro.models import sam_layer
 from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
-from repro.models.layers import (abstract_from_defs, axes_from_defs,
-                                 embed_apply, embed_defs, init_from_defs,
-                                 pdef, rms_norm, stack_defs)
+from repro.models.layers import (ParamDef, abstract_from_defs,
+                                 axes_from_defs, embed_apply, embed_defs,
+                                 init_from_defs, pdef, rms_norm, stack_defs)
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
@@ -48,8 +48,20 @@ def param_defs(cfg: ModelConfig):
     return defs
 
 
-def init_params(key, cfg: ModelConfig):
-    return init_from_defs(key, param_defs(cfg), _DTYPES[cfg.param_dtype])
+def init_params(key, cfg: ModelConfig, *, dtype: Optional[str] = None,
+                mesh=None):
+    """Random weights from ``key``, stored in ``dtype`` (default
+    ``cfg.param_dtype``). Serving passes the compute dtype: a model that
+    keeps no optimizer state holds no f32 copy and casts nothing per step.
+    ``mesh`` places every leaf by its logical axes as it is made."""
+    defs = param_defs(cfg)
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree.map(
+            lambda d: named_sharding(mesh, d.axes, d.shape), defs,
+            is_leaf=lambda x: isinstance(x, ParamDef))
+    return init_from_defs(key, defs, _DTYPES[dtype or cfg.param_dtype],
+                          shardings)
 
 
 def abstract_params(cfg: ModelConfig):
@@ -235,10 +247,9 @@ def init_memory_states(cfg: ModelConfig, batch: int, *,
     ``per_lane_step=True`` carries the SAM step counter as a (B, 1) vector
     so every lane stamps usage with its *own* session step — a session
     evicted and later restored into a different lane (launch/engine) then
-    reproduces the uninterrupted run's usage table bit-for-bit. The ref
-    kernel backend broadcasts the vector step; the fused Pallas write
-    kernel scalar-prefetches it and stamps per batch row, so per-lane
-    serving runs on any backend."""
+    reproduces the uninterrupted run's usage table bit-for-bit. Every
+    kernel backend's fused write stamps the vector step per batch row,
+    so per-lane serving runs on any backend."""
     if cfg.memory is None:
         return None
     n_groups = max(1, cfg.num_layers // cfg.memory.every_n_layers)
@@ -321,23 +332,32 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, mem_states=None):
         n_groups = len(mem_states)
         per = n_scan // n_groups
         mem_params = _cast(params["memory"], cfg)
-        new_mem, group_caches = [], []
+
+        # A group's layers are indexed out of the full stacks inside the
+        # loop, and the cache is updated in place: a static slice of the
+        # stacked weights per group would copy them (on a 16 GB chip the
+        # copies of a 4B model's weights do not fit beside the weights).
+        def layer_body(carry, i):
+            x, cache_all = carry
+            at = lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, False)  # noqa: E731
+            x, new_l = tfm.block_decode(jax.tree.map(at, blocks), cfg, x,
+                                        jax.tree.map(at, cache_all), pos)
+            cache_all = jax.tree.map(
+                lambda t, n: jax.lax.dynamic_update_index_in_dim(t, n, i, 0),
+                cache_all, new_l)
+            return (x, cache_all), None
+
+        new_mem = []
+        new_scan_cache = scan_cache
         for g in range(n_groups):
-            sl = jax.tree.map(
-                lambda t: jax.lax.slice_in_dim(t, g * per, (g + 1) * per,
-                                               axis=0), blocks)
-            cc = jax.tree.map(
-                lambda t: jax.lax.slice_in_dim(t, g * per, (g + 1) * per,
-                                               axis=0), scan_cache)
-            x, nc = jax.lax.scan(body, x, (sl, cc))
-            group_caches.append(nc)
+            (x, new_scan_cache), _ = jax.lax.scan(
+                layer_body, (x, new_scan_cache),
+                jnp.arange(g * per, (g + 1) * per))
             mp = jax.tree.map(lambda t: t[g], mem_params)
             st, out = sam_layer.memory_access(mp, cfg, x[:, 0],
                                               mem_states[g])
             new_mem.append(st)
             x = x + out[:, None, :].astype(x.dtype)
-        new_scan_cache = jax.tree.map(
-            lambda *ts: jnp.concatenate(ts, axis=0), *group_caches)
     else:
         x, new_scan_cache = jax.lax.scan(body, x, (blocks, scan_cache))
 

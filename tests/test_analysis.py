@@ -82,7 +82,7 @@ def test_count_primitives_kwargs_and_kernel_names():
         lambda *a: ops.fused_read(*a, 4, backend="pallas-interpret"),
         q, mem, beta)
     assert fused["pallas_call"] == 1
-    assert kernel_names(fused) == {"_sweep_kernel": 1}
+    assert kernel_names(fused) == {"fused_read_sweep": 1}
 
 
 def test_measure_flops_and_donation_fingerprint():

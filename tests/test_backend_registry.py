@@ -27,6 +27,17 @@ def test_resolve_env_var(monkeypatch):
     assert be.name == "pallas-interpret" and be.use_pallas and be.interpret
 
 
+def test_platform_default_is_pallas_on_tpu(monkeypatch):
+    """With no backend named, a TPU resolves to the compiled kernels and
+    every other platform to the oracle — never to the interpreter."""
+    monkeypatch.delenv(registry.ENV_VAR, raising=False)
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: "tpu")
+    be = registry.resolve(None)
+    assert be.name == "pallas" and be.use_pallas and not be.interpret
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: "cpu")
+    assert registry.resolve(None).name == "ref"
+
+
 def test_resolve_passthrough_instance():
     be = registry.get("pallas")
     assert registry.resolve(be) is be

@@ -10,6 +10,7 @@ import pytest
 from repro.checkpoint import (latest_step, restore_checkpoint,
                               save_checkpoint)
 from repro.distributed.elastic import rescale_batch, reshard_tree
+from repro.launch.mesh import make_mesh
 from repro.distributed.fault_tolerance import (ResilientLoop, StragglerPolicy,
                                                TransientError)
 
@@ -129,7 +130,7 @@ def test_rescale_to_mesh_relayouts_memory_state():
             "w": jnp.ones((4, 4))}
     axes = {"memory": (None, "mem_slots", "mem_word"),
             "w": ("batch", "embed")}
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     out = rescale_to_mesh(tree, axes, mesh, num_slots=n)
     assert out["memory"].shape == (2, n + 1, 4)         # canonical layout
     np.testing.assert_array_equal(np.asarray(out["memory"][:, :n]),
@@ -137,7 +138,7 @@ def test_rescale_to_mesh_relayouts_memory_state():
 
 
 def test_elastic_reshard_single_device():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jnp.ones((4, 4))}
     axes = {"w": ("batch", "embed")}
     out = reshard_tree(tree, axes, mesh)

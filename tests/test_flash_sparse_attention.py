@@ -36,7 +36,7 @@ def test_flash_attention_sweep(B, S, H, Hkv, D, qb, kb, rng_key):
     q = jax.random.normal(ks[0], (B, S, H, D))
     k = jax.random.normal(ks[1], (B, S, Hkv, D))
     v = jax.random.normal(ks[2], (B, S, Hkv, D))
-    out = flash_attention(q, k, v, q_block=qb, kv_block=kb)
+    out = flash_attention(q, k, v, q_block=qb, kv_block=kb, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(naive(q, k, v)),
                                atol=2e-5)
 
@@ -46,7 +46,7 @@ def test_flash_attention_bf16(rng_key):
     q = jax.random.normal(ks[0], (1, 64, 2, 16)).astype(jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 64, 2, 16)).astype(jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 64, 2, 16)).astype(jnp.bfloat16)
-    out = flash_attention(q, k, v, q_block=32, kv_block=32)
+    out = flash_attention(q, k, v, q_block=32, kv_block=32, interpret=True)
     ref = naive(q.astype(jnp.float32), k.astype(jnp.float32),
                 v.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(out, np.float32),

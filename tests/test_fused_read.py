@@ -184,7 +184,7 @@ def test_bf16_memory_reads_close_to_f32(backend):
 
 # ------------------------- structural dispatch guard ----------------------
 # The dispatch fingerprints (one pallas_call, zero top_k/sort, the
-# `_sweep_kernel` name) are declared on contracts in repro.analysis.paths;
+# `fused_read_sweep` name) are declared on contracts in repro.analysis.paths;
 # these tests run them through the shared checker so the guard and the
 # sweep share one source of truth. Each pairs with a ref/composed positive
 # control that passes only by tripping.
@@ -198,7 +198,7 @@ def _run(name):
 
 def test_exact_read_is_one_kernel_dispatch():
     """The acceptance guard: on the Pallas backend the exact read traces to
-    exactly one pallas_call (the `_sweep_kernel`) and NO top_k/sort; the
+    exactly one pallas_call (the `fused_read_sweep`) and NO top_k/sort; the
     composed/ref path (the positive control) contains a top_k."""
     report, detail = _run("sam_read_exact_kernel")
     assert report["ok"], detail

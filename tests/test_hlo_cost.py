@@ -52,9 +52,7 @@ def test_xla_builtin_undercounts_scan():
         return out
 
     x = jnp.ones((128, 128))
-    ca = jax.jit(scanned).lower(x).compile().cost_analysis()
-    # jax 0.4.x returns one properties dict per partition, as a list.
-    builtin = (ca[0] if isinstance(ca, (list, tuple)) else ca)["flops"]
+    builtin = jax.jit(scanned).lower(x).compile().cost_analysis()["flops"]
     ours = analyze(_hlo(scanned, x)).flops
     assert ours > 5 * builtin
 

@@ -36,6 +36,7 @@ from repro.core import unroll as unroll_lib
 from repro.core.cell import SAMCell, SDNCCell
 from repro.core.types import ControllerConfig, MemoryConfig
 from repro.distributed import mem_shard
+from repro.launch.mesh import make_mesh
 
 # bench_shard provides the 2D compile helpers (single source for the HLO
 # guard); `python -m pytest` puts the repo root on sys.path, bare `pytest`
@@ -53,7 +54,7 @@ TOL = 1e-5
 
 
 def _mesh28():
-    return jax.make_mesh((2, 8), ("data", "model"))
+    return make_mesh((2, 8), ("data", "model"))
 
 
 def _mesh18():

@@ -39,6 +39,7 @@ from repro.core import unroll as unroll_lib
 from repro.core.cell import SAMCell, SDNCCell
 from repro.core.types import ControllerConfig, MemoryConfig
 from repro.distributed import mem_shard
+from repro.launch.mesh import make_mesh
 
 # The HLO collective guard reuses the bench helpers (single source for the
 # O(K-not-N) guard — benchmarks/bench_shard.py); `python -m pytest` puts
@@ -56,11 +57,11 @@ TOL = 1e-5
 
 
 def _mesh8():
-    return jax.make_mesh((8,), ("model",))
+    return make_mesh((8,), ("model",))
 
 
 def _mesh24():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 @functools.lru_cache(maxsize=None)

@@ -42,6 +42,7 @@ from repro.core import sam as sam_lib  # noqa: E402
 from repro.core.types import ControllerConfig, MemoryConfig  # noqa: E402
 from repro.distributed import elastic, mem_shard  # noqa: E402
 from repro.launch.engine import SessionStore  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 pytestmark = pytest.mark.slow
 
@@ -182,7 +183,7 @@ def test_mesh_state_round_trip_bit_exact():
     store (canonicalize + host move), take it back, re-lay-out to the
     mesh — every logical row, usage entry, and ANN leaf bit-exact against
     the pre-eviction state."""
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     cfg = _cfg(ann="lsh")
     with mem_shard.memory_mesh(mesh, N):
         params = sam_lib.init_params(jax.random.PRNGKey(0), cfg)
@@ -215,8 +216,8 @@ def test_mesh_state_data_degree_change_bit_exact():
     logical leaf bit-exact. `rescale_batch` covers the same event's batch
     arithmetic: per-device batch stays fixed across the degree change."""
     b2 = 2                                    # divisible by the data degree
-    mesh24 = jax.make_mesh((2, 4), ("data", "model"))
-    mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh24 = make_mesh((2, 4), ("data", "model"))
+    mesh42 = make_mesh((4, 2), ("data", "model"))
     cfg = _cfg(ann="lsh")
     params = sam_lib.init_params(jax.random.PRNGKey(0), cfg)
     store = SessionStore(num_slots=N)

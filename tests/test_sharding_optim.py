@@ -14,17 +14,18 @@ from repro.core import ann as ann_lib
 from repro.core.types import LA_SCRATCH, MemoryConfig
 from repro.distributed import mem_shard
 from repro.distributed.sharding import logical_spec, mesh_rules, shard
+from repro.launch.mesh import make_mesh
 from repro.optim import optimizers as opt
 
 
 def test_logical_spec_resolution():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = logical_spec(("batch", "seq"), (8, 128), mesh)
     assert spec == P(("data",), None) or spec == P("data", None)
 
 
 def test_logical_spec_drops_nondividing_axes():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     # vocab 7 not divisible by ... 1 divides everything; use size-1 mesh but
     # simulate with a fake: divisibility logic is in _resolve.
     from repro.distributed.sharding import _resolve
