@@ -377,6 +377,10 @@ def restore_checkpoint(directory: str, template, step: int = None,
                           else jax.numpy.asarray(tmpl))
             continue
         arr = np.load(os.path.join(path, entry["file"]))
+        if arr.dtype.kind == "V":
+            # .npy does not keep an ml_dtypes type: bfloat16 reads back
+            # as raw 2-byte records. The manifest names the dtype.
+            arr = arr.view(jax.numpy.dtype(entry["dtype"]))
         if hasattr(tmpl, "shape") and arr.shape != tuple(tmpl.shape):
             # Path components render as ".memory" (GetAttrKey) or "memory"
             # (dict key) depending on the container — compare field names.

@@ -22,6 +22,9 @@ single-device engine restores into a mesh engine and vice versa. Row
 indices (`read_idx`) need no conversion: they are *global* slot ids in
 [0, N) under every layout (the mem_shard module contract).
 
+Every step and every session move records a profiler span, and the
+engine keeps host counters in `stats` (launch/engine/telemetry.py).
+
 Determinism contract (tested in tests/test_serve_engine.py): every decode
 and memory op is per-batch-row and sampling keys derive from
 (request seed, session token counter) only, so a request's token stream
@@ -46,6 +49,7 @@ from repro.distributed.sharding import mesh_rules
 from repro.models import lm
 from repro.launch.engine.scheduler import Request, Scheduler
 from repro.launch.engine.sessions import SessionStore
+from repro.launch.engine.telemetry import EngineStats, span, tree_nbytes
 from repro.launch.engine.stepfn import (make_engine_step, make_lane_insert,
                                         make_prefill_scan)
 
@@ -99,7 +103,7 @@ class ServeEngine:
                 num_slots=cfg.memory.num_slots if cfg.memory else None,
                 capacity=session_capacity, spill_dir=spill_dir)
         self._out: dict[int, list] = {}             # request id -> tokens
-        self.steps = 0
+        self.stats = EngineStats()
 
     def _init_params(self) -> None:
         """Random weights from ``param_seed`` in the compute dtype (serving
@@ -193,6 +197,10 @@ class ServeEngine:
             return ctx.shards
         return 1
 
+    @property
+    def steps(self) -> int:
+        return self.stats.steps
+
     # -- request API -------------------------------------------------------
 
     def submit(self, req: Request) -> Request:
@@ -207,20 +215,27 @@ class ServeEngine:
     def step(self) -> list:
         """Advance the batch one token; returns results of any requests
         that finished this step (possibly empty)."""
+        with span("serve.step", step_num=self.stats.steps,
+                  lanes=len(self.scheduler.active)):
+            return self._step()
+
+    def _step(self) -> list:
         for lane, req in self.scheduler.admit():
             self._admit_lane(lane, req)
         if not self.scheduler.active:
             return []
         self._prefill_scan_hop()
 
-        next_tok, logits, self.cache, self.mem = self._step_fn(
-            self.params, self.cache, self.mem, *self._step_inputs())
+        with span("serve.dispatch"):
+            next_tok, logits, self.cache, self.mem = self._step_fn(
+                self.params, self.cache, self.mem, *self._step_inputs())
         self.last_logits = logits     # (lanes, V); tests probe neighbours
         # Block on the sampled tokens: the tail-latency numbers the bench
         # records must measure compute, not JAX's async dispatch queue.
-        toks = np.asarray(next_tok)
+        with span("serve.wait"):
+            toks = np.asarray(next_tok)
         now = time.time()
-        self.steps += 1
+        self.stats.steps += 1
 
         finished = []
         for lane in sorted(self.scheduler.active):
@@ -322,6 +337,15 @@ class ServeEngine:
     # -- lane <-> session movement ----------------------------------------
 
     def _admit_lane(self, lane: int, req: Request) -> None:
+        warm = req.user in self.sessions
+        with span("serve.admit", req=req.id, lane=lane, warm=warm):
+            self._admit(lane, req)
+        if warm:
+            self.stats.admits_warm += 1
+        else:
+            self.stats.admits_cold += 1
+
+    def _admit(self, lane: int, req: Request) -> None:
         # Validate against the *stored* session before taking it: a
         # rejected request must leave the session in the store and hand
         # the lane back to the scheduler — previously `take` had already
@@ -357,23 +381,30 @@ class ServeEngine:
         """Cold session: zero KV rows, position 0, fresh memory state —
         including a cold (empty) ANN index for cells that carry one. One
         jitted dispatch (`make_lane_insert`), not one per state leaf."""
-        self.cache, self.mem = self._insert_fn(
-            self.cache, self.mem, lane, self._fresh_cache, self._zero_pos,
-            self._fresh_mem)
+        with span("serve.reset"), span("session.insert"):
+            self.cache, self.mem = self._insert_fn(
+                self.cache, self.mem, lane, self._fresh_cache,
+                self._zero_pos, self._fresh_mem)
         self._counters[lane] = 0
 
     def _restore_lane(self, lane: int, sess) -> None:
         """Warm session: re-lay the canonical-layout session out to the
         live shard count and insert it into `lane` — one jitted dispatch,
         like the cold reset."""
-        mem = None
-        if self.mem is not None:
-            mem = elastic.relayout_memory_state(
-                sess["mem"], self.cfg.memory.num_slots, self._live_shards)
-        self.cache, self.mem = self._insert_fn(
-            self.cache, self.mem, lane, sess["cache"],
-            jnp.asarray(sess["pos"]), mem)
+        with span("serve.restore"):
+            mem = None
+            if self.mem is not None:
+                with span("session.relayout"):
+                    mem = elastic.relayout_memory_state(
+                        sess["mem"], self.cfg.memory.num_slots,
+                        self._live_shards)
+            with span("session.insert"):
+                self.cache, self.mem = self._insert_fn(
+                    self.cache, self.mem, lane, sess["cache"],
+                    jnp.asarray(sess["pos"]), mem)
         self._counters[lane] = int(sess["counter"])
+        self.stats.bytes_to_device += tree_nbytes(
+            (sess["cache"], sess["pos"], mem))
 
     def _prefill_scan_hop(self) -> None:
         """Scan the shared mid-prompt stretch in one dispatch.
@@ -397,9 +428,11 @@ class ServeEngine:
         feed = np.zeros((self.lanes, n), np.int32)
         for lane, r in reqs.items():
             feed[lane] = r.prompt[r.prefill_done:r.prefill_done + n]
-        self.cache, self.mem = self._prefill_fn(
-            self.params, self.cache, self.mem, jnp.asarray(feed))
-        self.steps += n
+        with span("serve.hop", n=n):
+            self.cache, self.mem = self._prefill_fn(
+                self.params, self.cache, self.mem, jnp.asarray(feed))
+        self.stats.hop_dispatches += 1
+        self.stats.steps += n
         for lane, r in reqs.items():
             self._counters[lane] += n
             r.prefill_done += n
@@ -407,20 +440,27 @@ class ServeEngine:
 
     def _evict_lane(self, lane: int) -> None:
         req = self.scheduler.evict(lane)
-        sess = {
-            "cache": {k: v[:, lane:lane + 1]
-                      for k, v in self.cache.items() if k != "pos"},
-            "pos": self.cache["pos"][lane:lane + 1],
-            "counter": int(self._counters[lane]),
-        }
-        if self.mem is not None:
-            # No index remap needed: row indices (read_idx) are *global*
-            # slot ids in [0, N) under every layout (mem_shard module
-            # contract) — only the memory/usage buffers are re-laid-out.
-            sess["mem"] = tuple(
-                jax.tree.map(lambda t: t[lane:lane + 1], st)
-                for st in self.mem)
-        self.sessions.put(req.user, sess)
+        with span("serve.evict", req=req.id, lane=lane):
+            self._evict(lane, req)
+        self.stats.evictions += 1
+
+    def _evict(self, lane: int, req: Request) -> None:
+        with span("session.slice"):
+            sess = {
+                "cache": {k: v[:, lane:lane + 1]
+                          for k, v in self.cache.items() if k != "pos"},
+                "pos": self.cache["pos"][lane:lane + 1],
+                "counter": int(self._counters[lane]),
+            }
+            if self.mem is not None:
+                # No index remap needed: row indices (read_idx) are
+                # *global* slot ids in [0, N) under every layout (mem_shard
+                # module contract) — only the memory/usage buffers are
+                # re-laid-out.
+                sess["mem"] = tuple(
+                    jax.tree.map(lambda t: t[lane:lane + 1], st)
+                    for st in self.mem)
+        self.stats.bytes_to_host += self.sessions.put(req.user, sess)
 
     def _result(self, req: Request) -> dict:
         return {

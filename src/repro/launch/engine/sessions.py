@@ -45,6 +45,7 @@ import jax
 
 from repro.checkpoint import ckpt
 from repro.distributed import elastic
+from repro.launch.engine.telemetry import span, tree_nbytes
 
 
 def _host(tree):
@@ -65,6 +66,9 @@ class SessionStore:
     leaves (None = store trees as-is — memoryless sessions). ``capacity``
     bounds the number of *hot* (in-RAM) sessions; older sessions spill to
     ``spill_dir`` (required if capacity is set) and restore on ``take``.
+
+    ``spills`` counts sessions written to disk and ``restores`` sessions
+    read back from disk; a session taken from memory counts in neither.
     """
 
     def __init__(self, num_slots: Optional[int] = None,
@@ -84,15 +88,22 @@ class SessionStore:
 
     # -- core API ----------------------------------------------------------
 
-    def put(self, user: str, tree) -> None:
+    def put(self, user: str, tree) -> int:
         """Store `user`'s session. Slot-dimension leaves are re-laid-out to
-        the canonical (shards=1) layout and moved to host memory."""
-        if self.num_slots is not None:
-            tree = elastic.relayout_memory_state(tree, self.num_slots, 1)
-        self._hot[user] = _host(tree)
-        self._hot.move_to_end(user)
-        self._drop_spilled(user)          # the fresh copy supersedes it
-        self._maybe_spill()
+        the canonical (shards=1) layout and moved to host memory. Returns
+        the bytes of the stored (host) tree."""
+        with span("session.put"):
+            if self.num_slots is not None:
+                with span("session.relayout"):
+                    tree = elastic.relayout_memory_state(tree,
+                                                         self.num_slots, 1)
+            with span("session.to_host"):
+                host = _host(tree)
+            self._hot[user] = host
+            self._hot.move_to_end(user)
+            self._drop_spilled(user)          # the fresh copy supersedes it
+            self._maybe_spill()
+        return tree_nbytes(host)
 
     def take(self, user: str):
         """Remove and return `user`'s canonical-layout session tree (host
@@ -102,11 +113,7 @@ class SessionStore:
         if user in self._hot:
             return self._hot.pop(user)
         if user in self._spilled:
-            directory, template = self._spilled.pop(user)
-            tree, _ = ckpt.restore_checkpoint(directory, template)
-            shutil.rmtree(directory, ignore_errors=True)
-            self.restores += 1
-            return _host(tree)
+            return self._unspill(user)
         return None
 
     def peek(self, user: str):
@@ -118,11 +125,7 @@ class SessionStore:
         if user in self._hot:
             return self._hot[user]
         if user in self._spilled:
-            directory, template = self._spilled.pop(user)
-            tree, _ = ckpt.restore_checkpoint(directory, template)
-            shutil.rmtree(directory, ignore_errors=True)
-            self.restores += 1
-            self._hot[user] = _host(tree)
+            self._hot[user] = self._unspill(user)
             self._hot.move_to_end(user)
             self._maybe_spill()
             return self._hot[user]
@@ -144,6 +147,15 @@ class SessionStore:
         safe = "".join(c if c.isalnum() or c in "-_." else "_" for c in user)
         return os.path.join(self.spill_dir, f"session_{safe}")
 
+    def _unspill(self, user: str):
+        """Read `user`'s spilled session back from disk (host leaves)."""
+        with span("session.unspill"):
+            directory, template = self._spilled.pop(user)
+            tree, _ = ckpt.restore_checkpoint(directory, template)
+            shutil.rmtree(directory, ignore_errors=True)
+            self.restores += 1
+            return _host(tree)
+
     def _maybe_spill(self) -> None:
         if self.capacity is None:
             return
@@ -152,7 +164,9 @@ class SessionStore:
             directory = self._session_dir(user)
             mem_layout = (None if self.num_slots is None
                           else (self.num_slots, 1))
-            ckpt.save_checkpoint(directory, 0, tree, mem_layout=mem_layout)
+            with span("session.spill"):
+                ckpt.save_checkpoint(directory, 0, tree,
+                                     mem_layout=mem_layout)
             self._spilled[user] = (directory, _template(tree))
             self.spills += 1
 

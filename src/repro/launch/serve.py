@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import time
 
 import jax
@@ -118,7 +119,8 @@ def serve_continuous(arch: str, *, lanes: int = 4, requests: int = 8,
                      max_len: int = 128, use_reduced: bool = True,
                      seed: int = 0, greedy: bool = True, mesh=None):
     """Serve `requests` synthetic single-request users through the
-    continuous-batching engine and report aggregate throughput."""
+    continuous-batching engine and report aggregate throughput and the
+    engine's counters (`ServeEngine.stats`)."""
     import numpy as np
     from repro.launch.engine import Request, ServeEngine
 
@@ -136,12 +138,13 @@ def serve_continuous(arch: str, *, lanes: int = 4, requests: int = 8,
                     max_new_tokens=gen_len, greedy=greedy, sample_seed=i)
             for i in range(requests)])
         wall = time.time() - t0
-        steps = eng.steps
+        stats = dataclasses.asdict(eng.stats)
     total = sum(len(r["tokens"]) for r in results)
     return {
         "results": results,
         "wall_s": wall,
-        "steps": steps,
+        "steps": stats["steps"],
+        "stats": stats,
         "tok_per_s": total / max(wall, 1e-9),
     }
 
@@ -176,8 +179,9 @@ def main():
                                prompt_len=args.prompt_len,
                                gen_len=args.gen_len,
                                greedy=not args.sample, mesh=mesh)
+        stats = " ".join(f"{k}={v}" for k, v in res["stats"].items())
         print(f"served {len(res['results'])} requests in {res['steps']} "
-              f"steps; {res['tok_per_s']:.1f} tok/s")
+              f"steps; {res['tok_per_s']:.1f} tok/s; {stats}")
     else:
         res = serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
                     gen_len=args.gen_len, greedy=not args.sample, mesh=mesh)

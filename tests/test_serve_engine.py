@@ -375,3 +375,6 @@ def test_serve_continuous_entrypoint():
     assert len(res["results"]) == 3
     assert all(len(r["tokens"]) == 2 for r in res["results"])
     assert res["tok_per_s"] > 0
+    st = res["stats"]
+    assert (st["admits_cold"], st["admits_warm"], st["evictions"]) == (3, 0, 3)
+    assert st["steps"] == res["steps"] and st["bytes_to_host"] > 0

@@ -151,6 +151,20 @@ def test_spill_and_restore_counts():
             np.asarray, elastic.relayout_memory_state(s0, N, 1)))
 
 
+def test_spilled_bfloat16_leaves_come_back_as_bfloat16():
+    """A bf16 KV cache (the serving compute dtype) spilled to `.npy` reads
+    back as raw 2-byte records unless the manifest's dtype is applied."""
+    kv = jnp.arange(24, dtype=jnp.bfloat16).reshape(2, 3, 4) / 7
+    with tempfile.TemporaryDirectory() as tmp:
+        store = SessionStore(capacity=1, spill_dir=tmp)
+        assert store.put("a", {"k": kv}) == kv.nbytes
+        store.put("b", {"k": kv + 1})        # a spills to disk
+        got = store.take("a")["k"]
+    assert store.restores == 1
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(kv))
+
+
 def test_capacity_requires_spill_dir():
     with pytest.raises(ValueError):
         SessionStore(num_slots=N, capacity=2)
