@@ -11,10 +11,15 @@ These kernels collapse the whole read into **one** `pallas_call`:
   per-head top-K in VMEM scratch (values, indices, and the raw candidate
   *rows*, so no second gather pass ever touches HBM — the selection
   helpers are shared with `kernels/topk_read.py`), and the final tile
-  applies key strength, softmax, and the weighted sum in-register. HBM
-  traffic is one O(N·W) memory stream per batch row, shared by its H
-  heads — the intermediates (sims, top-K merge buffers, gathered rows)
-  never exist outside VMEM.
+  orders the top-K and applies key strength, softmax, and the weighted
+  sum in-register. HBM traffic is one O(N·W) memory stream per batch row,
+  shared by its H heads — the intermediates (sims, top-K merge buffers,
+  gathered rows) never exist outside VMEM. The merge is gated
+  (`topk_read.sweep_tile`): only rows that beat their head's running K-th
+  value strictly enter, so a tile with no entrant does no merge work, and
+  a tile of zero rows is not scored once every head's K-th value is at
+  least 0; exact, bit for bit.
+  ``block_n`` defaults to `sweep_block`, derived from N and W.
 
 * `fused_read_candidates` — the ANN-mode read over a pre-deduped signed
   candidate set from the LSH index. The candidate ids are scalar-
@@ -53,10 +58,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_read import CONSUMED, merge_topk, sims_tile
+from repro.kernels.topk_read import (CONSUMED, reset_topk, sorted_topk,
+                                     sweep_tile)
 
 _NEG = -1e9                # finish_candidate_read's invalid-selection mask
 _IMAX = jnp.iinfo(jnp.int32).max
+# VMEM for a sweep tile's f32 working copy: rows of every storage dtype are
+# upcast to f32 in VMEM, and the one-hot row picks and the HIGHEST-precision
+# scoring hold several such copies, so the f32 tile bounds the block (an
+# 8,192 x 128 f32 tile, 4 MiB, does not fit the v5e's scoped VMEM). A row
+# takes at least 128 lanes of VMEM, whatever its width.
+_TILE_F32_BYTES = 1 << 20
+# The merge gate skips whole tiles, so a sweep keeps this many at least.
+_MIN_TILES = 16
+
+
+def sweep_block(n: int, w: int) -> int:
+    """Rows per tile of the exact sweep over ``n`` rows of width ``w``:
+    512, doubled while the tile still divides ``n``, its f32 working copy
+    stays within `_TILE_F32_BYTES` and ``n`` still spans `_MIN_TILES`
+    tiles; at most ``n``. Larger tiles cut the grid's fixed cost per step;
+    smaller ones let the gate skip more of a memory that is zero past its
+    written rows, and cost less per insertion (2,048 rows at N=65,536,
+    W=128)."""
+    bn = 512
+    row_bytes = max(w, 128) * 4
+    while (n % (2 * bn) == 0 and 2 * bn * row_bytes <= _TILE_F32_BYTES
+           and n // (2 * bn) >= _MIN_TILES):
+        bn *= 2
+    return min(bn, n)
 
 
 def _norm_row(x):
@@ -97,38 +127,33 @@ def _sweep_kernel(q_ref, m_ref, beta_ref, *rest, k: int, block_n: int,
 
     @pl.when(t == 0)
     def _init():
-        vals_s[...] = jnp.full(vals_s.shape, CONSUMED, jnp.float32)
-        idx_s[...] = jnp.zeros(idx_s.shape, jnp.int32)
+        reset_topk(vals_s, idx_s)
         rows_s[...] = jnp.zeros(rows_s.shape, jnp.float32)
 
     # In-VMEM upcast/dequantization: the HBM stream stays in the storage
     # dtype (int8 rows + one f32 scale per row, ~4x less than f32 rows).
-    m = m_ref[...].astype(jnp.float32)
-    sims = sims_tile(q_ref[...], m, scale)
-    vals, idx, rows = merge_topk(sims, t * block_n, vals_s[...], idx_s[...],
-                                 m=m, scale=scale,
-                                 rows=[rows_s[i] for i in range(k)])
-    vals_s[...] = vals
-    idx_s[...] = idx
-    for i in range(k):
-        rows_s[i] = rows[i]
+    sweep_tile(q_ref[...], m_ref[...].astype(jnp.float32), t * block_n,
+               vals_s, idx_s, scale=scale, rows_ref=rows_s)
 
     @pl.when(t == tiles - 1)
     def _emit():
+        vals, idx, rows = sorted_topk(vals_s[...], idx_s[...],
+                                      [rows_s[i] for i in range(k)])
         # Exact selections are always valid (every swept row is real).
-        w = _softmax_tail(vals_s[...], True, beta_ref[...])
-        read = w[:, 0:1] * rows_s[0]
+        w = _softmax_tail(vals, True, beta_ref[...])
+        read = w[:, 0:1] * rows[0]
         for i in range(1, k):
-            read = read + w[:, i:i + 1] * rows_s[i]
+            read = read + w[:, i:i + 1] * rows[i]
         read_ref[...] = read
         w_ref[...] = w
-        idx_ref[...] = idx_s[...]
+        idx_ref[...] = idx
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret",
                                              "valid_n"))
 def fused_read_sweep(q: jax.Array, mem: jax.Array, beta: jax.Array, *,
-                     k: int, block_n: int = 512, interpret: bool = False,
+                     k: int, block_n: Optional[int] = None,
+                     interpret: bool = False,
                      valid_n: Optional[int] = None,
                      mem_scale: Optional[jax.Array] = None):
     """q: (B, H, W), mem: (B, N, W), beta: (B, H) -> (read (B, H, W) f32,
@@ -138,9 +163,11 @@ def fused_read_sweep(q: jax.Array, mem: jax.Array, beta: jax.Array, *,
     sweep to rows [0, valid_n) of a scratch-row buffer. ``mem_scale``
     (B, N) marks int8 rows: each tile's rows are dequantized in VMEM
     (``row * scale``) — still one dispatch, the HBM stream drops to int8
-    rows plus one f32 scalar per row."""
+    rows plus one f32 scalar per row. ``block_n`` defaults to
+    `sweep_block`'s."""
     B, H, W = q.shape
     N = mem.shape[1] if valid_n is None else valid_n
+    block_n = sweep_block(N, W) if block_n is None else block_n
     assert N % block_n == 0, (N, block_n)
     assert block_n >= k, (block_n, k)
     tiles = N // block_n

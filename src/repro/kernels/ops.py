@@ -66,6 +66,7 @@ from repro.kernels import ref
 from repro.kernels.fused_read import \
     fused_read_candidates as fused_read_cand_pallas
 from repro.kernels.fused_read import fused_read_sweep as fused_read_pallas
+from repro.kernels.fused_read import sweep_block
 from repro.kernels.lsh_hash import lsh_hash as lsh_hash_pallas
 from repro.kernels.registry import BackendSpec, resolve
 from repro.kernels.scatter_rows import scatter_rows as scatter_rows_pallas
@@ -228,7 +229,7 @@ def lra_topn(last_access, n: int, *, backend: BackendSpec = None,
 # --------------------------------------------------------------------------
 
 def fused_read(q, mem, beta, k: int, *, cand_idx=None,
-               backend: BackendSpec = None, block_n: int = 512,
+               backend: BackendSpec = None, block_n: int = None,
                valid_n: int = None, mem_scale=None):
     """The whole sparse read in one kernel dispatch. q: (B, H, W),
     mem: (B, N, W), beta: (B, H) -> (read (B, H, W) f32, weights (B, H, K),
@@ -240,9 +241,11 @@ def fused_read(q, mem, beta, k: int, *, cand_idx=None,
     ANN-mode read with grid independent of N (`fused_read_candidates`).
     Selection is non-differentiable; read/weights carry the composed
     path's exact gradients (custom VJP re-derives `ref.sparse_read_tail`
-    from the recorded indices). Falls back to the jnp oracle when N is
-    not divisible by the clamped block size (exact) or C < k (ANN) —
-    identical results, composed execution.
+    from the recorded indices). The exact sweep's rows per tile are
+    ``block_n`` clamped to N, or by default `fused_read.sweep_block`'s,
+    derived from N and W. Falls back to the jnp oracle when N is not
+    divisible by the block size (exact) or C < k (ANN) — identical
+    results, composed execution.
 
     Int8 memory storage: ``mem_scale`` (B, N) f32 per-row scales mark int8
     rows. Both Pallas kernels dequantize **inside** the (still single)
@@ -269,10 +272,11 @@ def fused_read(q, mem, beta, k: int, *, cand_idx=None,
         kw = _opt_kw(mem_scale=mem_scale)
         if valid_n is not None and not _accepts_kw(impl, "valid_n"):
             out = impl(q, mem[:, :valid_n], beta, k, cand_idx=cand_idx,
-                       block_n=block_n, **kw)
+                       **_opt_kw(block_n=block_n), **kw)
         else:
-            out = impl(q, mem, beta, k, cand_idx=cand_idx, block_n=block_n,
-                       **_opt_kw(valid_n=valid_n, mem_scale=mem_scale))
+            out = impl(q, mem, beta, k, cand_idx=cand_idx,
+                       **_opt_kw(block_n=block_n, valid_n=valid_n,
+                                 mem_scale=mem_scale))
         read, w, idx = out
         return read, w, _detach_int(idx)
     if cand_idx is not None:
@@ -298,12 +302,13 @@ def fused_read(q, mem, beta, k: int, *, cand_idx=None,
             else ref._deq_view(mem, mem_scale)
         _, idx = topk_read(jax.lax.stop_gradient(q),
                            jax.lax.stop_gradient(mv), k, backend=be,
-                           block_n=block_n, valid_n=valid_n)
+                           valid_n=valid_n, **_opt_kw(block_n=block_n))
         read, w = ref.sparse_read_tail(q, mem, beta, idx,
                                        mem_scale=mem_scale)
         return read, w, _detach_int(idx)
     nv = mem.shape[1] if valid_n is None else valid_n
-    bn = min(block_n, nv)
+    bn = sweep_block(nv, mem.shape[2]) if block_n is None \
+        else min(block_n, nv)
     if be.use_pallas and nv % bn == 0 and bn >= k:
         if mem_scale is not None:
             out = _fused_read_sweep_q_vjp(q, mem, mem_scale, beta, k, bn,

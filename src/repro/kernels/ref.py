@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.quant import dequantize_rows, quantize_rows
 
@@ -82,6 +83,43 @@ def fused_read_ref(q: jax.Array, mem: jax.Array, beta: jax.Array, k: int,
         jax.lax.stop_gradient(_deq_view(mv, sv)), k)
     read, w = sparse_read_tail(q, mem, beta, idx, mem_scale=mem_scale)
     return read, w, idx
+
+
+def sweep_merge_steps(q, mem, k: int, block_n: int, valid_n=None,
+                      mem_scale=None):
+    """NumPy count of the exact sweep's work, lane by lane, as
+    `topk_read.sweep_tile` gates it (a measuring aid; no step calls it).
+
+    q: (B, H, W), mem: (B, N, W) (int8 rows with ``mem_scale`` (B, N)),
+    swept over rows [0, valid_n) in tiles of ``block_n``. Returns
+    (scored, merged, insertions), each (B,) int: the tiles scored (a
+    nonzero row, or a head whose running K-th value is below 0), the tiles
+    where some head has an entrant (a score strictly above its running
+    K-th value), and the insertions those tiles run (min(K, most entrants
+    of any head), summed). Scores are the kernel's formula in float64, so
+    a count can differ from the kernel's only at a near-tie."""
+    q = np.asarray(q, np.float64)
+    mem = np.asarray(mem, np.float64)
+    B, H, _ = q.shape
+    n = mem.shape[1] if valid_n is None else valid_n
+    scale = np.ones((B, n)) if mem_scale is None \
+        else np.asarray(mem_scale, np.float64)[:, :n]
+    qn = q / np.sqrt(np.sum(q * q, -1, keepdims=True) + 1e-6)
+    counts = np.zeros((3, B), np.int64)
+    for b in range(B):
+        top = np.full((H, k), -np.inf)                    # running top-K
+        for t in range(0, n, block_n):
+            m, s = mem[b, t:t + block_n], scale[b, t:t + block_n]
+            if not m.any() and top.min() >= 0:
+                continue
+            counts[0, b] += 1
+            sims = (qn[b] @ m.T) * s / np.sqrt(np.sum(m * m, -1) * s * s
+                                               + 1e-6)
+            steps = min(k, int((sims > top[:, -1:]).sum(1).max()))
+            counts[1, b] += steps > 0
+            counts[2, b] += steps
+            top = -np.sort(-np.concatenate([top, sims], 1), 1)[:, :k]
+    return tuple(counts)
 
 
 def fused_read_candidates_ref(q: jax.Array, mem: jax.Array, beta: jax.Array,

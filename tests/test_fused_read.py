@@ -104,6 +104,167 @@ def test_exact_duplicate_rows_tie_break_like_top_k():
     assert np.array_equal(np.asarray(i_ref), np.asarray(i_pal))
 
 
+# ------------------------ gated merge: exactness --------------------------
+# The sweep merges a tile only where its rows enter a head's running top-K
+# (kernels/topk_read.py::sweep_tile). Each case below must give what
+# `lax.top_k` gives over the kernel's own scores, bit for bit: the indices,
+# the weights of the softmax tail over the selected scores, and the read.
+
+SWEEP_K, SWEEP_BN, SWEEP_N = 8, 128, 1024
+
+
+def _sweep_case(name, key, B=2, H=4, N=SWEEP_N, W=16):
+    """(q, mem (B, N+1, W) with garbage on the scratch row N, beta)."""
+    kq, km, kb, kn = jax.random.split(key, 4)
+    q = np.asarray(jax.random.normal(kq, (B, H, W)))
+    mem = np.asarray(jax.random.normal(km, (B, N + 1, W))).copy()
+    if name == "zero_tail":                # most tiles hold only zero rows
+        mem[:, 40:N] = 0.0
+    elif name == "late_entrants":          # full memory, winners at the end
+        mem[:, N - 3] = q[:, 0]
+        mem[:, N - 70] = q[:, 1]
+        mem[:, N - SWEEP_BN - 5] = q[:, 2]
+    elif name == "crowded_tile":           # > K entrants in one tile
+        near = q[:, :1] + 0.05 * np.asarray(
+            jax.random.normal(kn, (B, 3 * SWEEP_K, W)))
+        mem[:, 5 * SWEEP_BN + 7:5 * SWEEP_BN + 7 + 3 * SWEEP_K] = near
+    elif name == "ties":                   # later duplicates must lose
+        mem[:, 2 * SWEEP_BN + 11] = q[:, 0]
+        mem[:, 2 * SWEEP_BN + 12:2 * SWEEP_BN + 12 + SWEEP_K] = q[:, 0, None]
+        mem[:, 6 * SWEEP_BN:6 * SWEEP_BN + 2 * SWEEP_K] = q[:, 0, None]
+        mem[:, 3 * SWEEP_BN + 1:3 * SWEEP_BN + 40] = mem[:, 1:40]
+        mem[:, N - SWEEP_BN:N] = mem[:, 0:SWEEP_BN]
+    elif name == "all_negative":           # zero rows win every head
+        q = np.abs(q)
+        mem[:, :N] = np.where(np.arange(N)[:, None] % 37 == 0,
+                              -np.abs(mem[:, :N]), 0.0)
+    mem[:, N] = 1e3 * q[:, 0]              # the scratch row: never swept
+    beta = jax.random.uniform(kb, (B, H), minval=1.0, maxval=3.0)
+    return jnp.asarray(q), mem, beta
+
+
+def _stored(mem, dtype):
+    """Rows as stored in `dtype` (+ per-row scales for int8) and the f32
+    rows they dequantize to."""
+    if dtype == "int8":
+        from repro.core.quant import dequantize_rows, quantize_rows
+        rows, scale = quantize_rows(jnp.asarray(mem))
+        return rows, scale, dequantize_rows(rows, scale)
+    rows = jnp.asarray(mem).astype(dtype)
+    return rows, None, rows.astype(jnp.float32)
+
+
+def _kernel_scores(q, rows, scale, n):
+    """The sweep's scores over rows [0, n), tile by tile and lane by lane,
+    in the kernel's own formula and ops (normalize(q) · m over the row
+    norm at HIGHEST, the int8 scale applied), jitted as the interpreted
+    kernel is."""
+    from repro.kernels.topk_read import _dot_nt
+
+    @jax.jit
+    def tile(q, m, s):
+        qn = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6)
+        m = m.astype(jnp.float32)
+        dot = _dot_nt(qn, m)
+        sq = _dot_nt(jnp.ones((1, m.shape[1]), jnp.float32), m * m)
+        if s is not None:
+            dot, sq = dot * s, sq * (s * s)
+        return dot * jax.lax.rsqrt(sq + 1e-6)
+
+    return jnp.stack([jnp.concatenate(
+        [tile(q[b], rows[b, t:t + SWEEP_BN],
+              None if scale is None else scale[b, None, t:t + SWEEP_BN])
+         for t in range(0, n, SWEEP_BN)], -1) for b in range(q.shape[0])])
+
+
+SWEEP_CASES = ["zero_tail", "late_entrants", "crowded_tile", "ties",
+               "all_negative"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("case", SWEEP_CASES)
+def test_gated_sweep_is_exact(case, dtype):
+    from repro.kernels import ref
+    from repro.kernels.fused_read import _softmax_tail, fused_read_sweep
+    from repro.kernels.topk_read import topk_read
+
+    q, mem, beta = _sweep_case(case, jax.random.PRNGKey(
+        SWEEP_CASES.index(case)))
+    rows, scale, deq = _stored(mem, dtype)
+    N, K = SWEEP_N, SWEEP_K
+    read, w, idx = fused_read_sweep(q, rows, beta, k=K, block_n=SWEEP_BN,
+                                    interpret=True, valid_n=N,
+                                    mem_scale=scale)
+
+    # The oracle: lax.top_k over the kernel's own scores, then the tail.
+    vals, want_idx = jax.lax.top_k(_kernel_scores(q, rows, scale, N), K)
+    @jax.jit
+    @jax.vmap
+    def tail(v, b, picked):                 # the kernel's emit, per lane
+        w = _softmax_tail(v, True, b[:, None])
+        read = w[:, 0:1] * picked[:, 0]
+        for i in range(1, K):
+            read = read + w[:, i:i + 1] * picked[:, i]
+        return read, w
+
+    want_read, want_w = tail(vals, beta, jnp.take_along_axis(
+        deq[:, None, :N], want_idx[..., None], 2))
+    assert np.array_equal(np.asarray(idx), np.asarray(want_idx))
+    assert np.array_equal(np.asarray(w), np.asarray(want_w))
+    assert np.array_equal(np.asarray(read), np.asarray(want_read))
+
+    # ... which is the composed read's selection (its own rounding).
+    r_read, r_w, r_idx = ref.fused_read_ref(q, rows, beta, K, valid_n=N,
+                                            mem_scale=scale)
+    assert np.array_equal(np.asarray(idx), np.asarray(r_idx))
+    np.testing.assert_allclose(np.asarray(w), np.asarray(r_w), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(read), np.asarray(r_read),
+                               atol=1e-5)
+
+    if scale is None:                      # topk_read shares the merge
+        tv, ti = topk_read(q, rows, k=K, block_n=SWEEP_BN, interpret=True,
+                           valid_n=N)
+        assert np.array_equal(np.asarray(ti), np.asarray(want_idx))
+        assert np.array_equal(np.asarray(tv), np.asarray(vals))
+
+    scored, merged, inserted = ref.sweep_merge_steps(
+        q, rows if scale is None else rows.astype(jnp.float32), K, SWEEP_BN,
+        valid_n=N, mem_scale=scale)
+    assert (merged <= scored).all() and (inserted <= K * merged).all()
+    assert (merged >= 1).all() and (inserted >= K).all()  # tile 0 fills
+    if case in ("zero_tail", "all_negative"):
+        # Past the first tile of zero rows nothing can enter any head.
+        assert (merged <= 2).all(), merged
+    if case == "zero_tail":
+        assert (scored <= 2).all(), scored   # zero tiles are not scored
+    if case == "crowded_tile":
+        assert (inserted >= 2 * K).all(), inserted
+
+
+def test_sweep_merge_steps_counts_a_full_memory():
+    """The counter on a full random memory: every tile is scored, and a
+    K-wide merge runs only while the top-K is filling."""
+    from repro.kernels import ref
+    q, mem, _ = _sweep_case("full", jax.random.PRNGKey(5))
+    scored, merged, inserted = ref.sweep_merge_steps(q, mem, SWEEP_K,
+                                                     SWEEP_BN, valid_n=SWEEP_N)
+    tiles = SWEEP_N // SWEEP_BN
+    assert (scored == tiles).all()
+    assert (merged <= tiles).all() and (inserted < SWEEP_K * tiles).all()
+
+
+@pytest.mark.parametrize("n,w,block", [
+    (65536, 128, 2048),      # the served memory: 32 tiles of 1 MiB f32
+    (2 ** 20, 32, 2048),     # narrow rows still take 128 lanes of VMEM
+    (16384, 128, 1024),      # kept at 16 tiles for the gate to skip
+    (2560, 128, 512),        # 1,024 does not divide: the old block
+    (64, 8, 64),             # a tiny memory is one tile
+])
+def test_sweep_block_is_derived_from_n_and_w(n, w, block):
+    from repro.kernels.fused_read import sweep_block
+    assert sweep_block(n, w) == block
+
+
 # --------------------------- candidate read -------------------------------
 
 def _cand_case(key, B=2, H=2, N=64, W=16, C=12):
